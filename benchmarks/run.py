@@ -12,6 +12,8 @@ import os
 import time
 import traceback
 
+from repro.launch.compile_cache import enable_compile_cache
+
 from benchmarks import (
     bench_ablation,
     bench_bank,
@@ -49,6 +51,7 @@ def main() -> None:
     ap.add_argument("--only", default=None,
                     help="comma-separated benchmark names")
     args = ap.parse_args()
+    enable_compile_cache()
 
     names = list(BENCHES)
     if args.only:

@@ -21,7 +21,10 @@ def main():
     ap.add_argument("--multi-pod", action="store_true")
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.launch.dryrun import dryrun_one
+
+    enable_compile_cache()
 
     rec = dryrun_one(args.arch, args.shape, multi_pod=args.multi_pod)
     t = rec["roofline"]

@@ -37,11 +37,13 @@ from repro.core.bank_builder import (
 )
 from repro.core.prompt_bank import PromptBank
 from repro.data import LoaderConfig, TaskLoader
+from repro.launch.compile_cache import enable_compile_cache
 from repro.train.pretrain import pretrain
 from repro.tuning import PromptTuner, activation_features
 
 
 def main():
+    enable_compile_cache()
     print("== 1. testbed LLM + Prompt Bank")
     pre = pretrain("gpt2-base", cache=True)
     t0 = time.time()

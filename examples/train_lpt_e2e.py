@@ -18,6 +18,7 @@ import numpy as np
 from repro.config import TuneConfig
 from repro.configs import get_config
 from repro.data import LoaderConfig, TaskLoader, TaskSpec, batch_to_jnp
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.steps import make_train_step
 from repro.models import build_model
 from repro.train.checkpoint import save_checkpoint
@@ -39,6 +40,7 @@ def main():
     ap.add_argument("--batch", type=int, default=8)
     ap.add_argument("--ckpt", default="artifacts/e2e_prompt.npz")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = hundred_m_config()
     model = build_model(cfg)

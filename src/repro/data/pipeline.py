@@ -6,6 +6,7 @@ host count is 1 and the loader degrades to simple batching. Prefetch is a
 simple double-buffer (thread-free: CPU-bound synthetic generation)."""
 from __future__ import annotations
 
+import zlib
 from dataclasses import dataclass
 from typing import Dict, Iterator, Optional
 
@@ -22,6 +23,12 @@ class LoaderConfig:
     num_hosts: int = 1
 
 
+def _task_key(spec: TaskSpec) -> int:
+    # zlib.crc32, not hash(): str hashing is salted per process, which
+    # made every process draw different batches from the same seed
+    return zlib.crc32(spec.task_id.encode())
+
+
 class TaskLoader:
     """Infinite iterator of batches for one LPT task."""
 
@@ -30,7 +37,7 @@ class TaskLoader:
         self.spec = spec
         self.cfg = cfg
         self._rng = np.random.default_rng(
-            np.random.SeedSequence([cfg.seed, hash(spec.task_id) & 0x7FFFFFFF])
+            np.random.SeedSequence([cfg.seed, _task_key(spec)])
         )
 
     def __iter__(self) -> Iterator[Dict]:
@@ -46,6 +53,6 @@ class TaskLoader:
     def eval_batch(self, n: int, seed: int = 1234) -> Dict:
         """Fixed evaluation set (the Eqn-1 D_eval, e.g. 16 samples)."""
         rng = np.random.default_rng(
-            np.random.SeedSequence([seed, hash(self.spec.task_id) & 0x7FFFFFFF])
+            np.random.SeedSequence([seed, _task_key(self.spec)])
         )
         return sample_batch(self.spec, rng, n)

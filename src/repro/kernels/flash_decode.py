@@ -72,13 +72,13 @@ def _kernel(meta_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref,
         ok &= kpos > q_pos - window
     s = jnp.where(ok, s, NEG_INF)
 
-    m_prev = m_ref[...]
-    m_new = jnp.maximum(m_prev, s.max(axis=-1))
+    m_prev = m_ref[...]                                   # (G', 1)
+    m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
     alpha = jnp.exp(m_prev - m_new)
-    p = jnp.exp(s - m_new[:, None])
+    p = jnp.exp(s - m_new)
     p = jnp.where(ok, p, 0.0)          # exp(NEG_INF - NEG_INF) = 1 guard
-    l_ref[...] = l_ref[...] * alpha + p.sum(axis=-1)
-    acc_ref[...] = acc_ref[...] * alpha[:, None] + jax.lax.dot_general(
+    l_ref[...] = l_ref[...] * alpha + p.sum(axis=-1, keepdims=True)
+    acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
         p, v, (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32,
     )
@@ -87,9 +87,9 @@ def _kernel(meta_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref,
     @pl.when(ik == nk - 1)
     def _finish():
         l = l_ref[...]
-        denom = jnp.maximum(l, 1e-30)[:, None]
+        denom = jnp.maximum(l, 1e-30)
         o_ref[0, 0, 0] = (acc_ref[...] / denom).astype(o_ref.dtype)
-        lse_ref[0, 0, 0] = jnp.where(l > 0.0, m_ref[...] + jnp.log(denom[:, 0]),
+        lse_ref[0, 0, 0] = jnp.where(l > 0.0, m_ref[...] + jnp.log(denom),
                                      NEG_INF)
 
 
@@ -168,18 +168,19 @@ def flash_decode(q: jax.Array, k: jax.Array, v: jax.Array, *,
         ],
         out_specs=[
             pl.BlockSpec((1, 1, 1, gq, hd), lambda b, h, s, j: (b, h, s, 0, 0)),
-            pl.BlockSpec((1, 1, 1, gq), lambda b, h, s, j: (b, h, s, 0)),
+            pl.BlockSpec((1, 1, 1, gq, 1),
+                         lambda b, h, s, j: (b, h, s, 0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((B, Hkv, nsplit, gq, hd), jnp.float32),
-            jax.ShapeDtypeStruct((B, Hkv, nsplit, gq), jnp.float32),
+            jax.ShapeDtypeStruct((B, Hkv, nsplit, gq, 1), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((gq,), jnp.float32),               # running max
-            pltpu.VMEM((gq,), jnp.float32),               # running sum
+            pltpu.VMEM((gq, 1), jnp.float32),             # running max
+            pltpu.VMEM((gq, 1), jnp.float32),             # running sum
             pltpu.VMEM((gq, hd), jnp.float32),            # accumulator
         ],
         interpret=interpret,
     )(meta, qg, k, v)
-    out = combine_partials(o_part, lse, axis=2)           # (B, Hkv, gq, hd)
+    out = combine_partials(o_part, lse[..., 0], axis=2)   # (B, Hkv, gq, hd)
     return out[:, :, :G].reshape(B, H, hd).astype(q.dtype)

@@ -70,13 +70,13 @@ def _kernel(meta_ref, ql_ref, qp_ref, ckv_ref, kpe_ref, o_ref, lse_ref,
     ok = (kpos < kv_len) & (kpos <= q_pos)
     s = jnp.where(ok, s, NEG_INF)
 
-    m_prev = m_ref[...]
-    m_new = jnp.maximum(m_prev, s.max(axis=-1))
+    m_prev = m_ref[...]                                   # (H', 1)
+    m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
     alpha = jnp.exp(m_prev - m_new)
-    p = jnp.exp(s - m_new[:, None])
+    p = jnp.exp(s - m_new)
     p = jnp.where(ok, p, 0.0)          # fully-masked tile: exp(0) guard
-    l_ref[...] = l_ref[...] * alpha + p.sum(axis=-1)
-    acc_ref[...] = acc_ref[...] * alpha[:, None] + jax.lax.dot_general(
+    l_ref[...] = l_ref[...] * alpha + p.sum(axis=-1, keepdims=True)
+    acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
         p, ckv, (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32,
     )
@@ -85,9 +85,9 @@ def _kernel(meta_ref, ql_ref, qp_ref, ckv_ref, kpe_ref, o_ref, lse_ref,
     @pl.when(ik == nk - 1)
     def _finish():
         l = l_ref[...]
-        denom = jnp.maximum(l, 1e-30)[:, None]
+        denom = jnp.maximum(l, 1e-30)
         o_ref[0, 0] = (acc_ref[...] / denom).astype(o_ref.dtype)
-        lse_ref[0, 0] = jnp.where(l > 0.0, m_ref[...] + jnp.log(denom[:, 0]),
+        lse_ref[0, 0] = jnp.where(l > 0.0, m_ref[...] + jnp.log(denom),
                                   NEG_INF)
 
 
@@ -144,18 +144,18 @@ def mla_decode(q_lat: jax.Array, q_pe: jax.Array, ckv: jax.Array,
         ],
         out_specs=[
             pl.BlockSpec((1, 1, nh, r), lambda b, s, j: (b, s, 0, 0)),
-            pl.BlockSpec((1, 1, nh), lambda b, s, j: (b, s, 0)),
+            pl.BlockSpec((1, 1, nh, 1), lambda b, s, j: (b, s, 0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((B, nsplit, nh, r), jnp.float32),
-            jax.ShapeDtypeStruct((B, nsplit, nh), jnp.float32),
+            jax.ShapeDtypeStruct((B, nsplit, nh, 1), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((nh,), jnp.float32),               # running max
-            pltpu.VMEM((nh,), jnp.float32),               # running sum
+            pltpu.VMEM((nh, 1), jnp.float32),             # running max
+            pltpu.VMEM((nh, 1), jnp.float32),             # running sum
             pltpu.VMEM((nh, r), jnp.float32),             # latent accumulator
         ],
         interpret=interpret,
     )(meta, q_lat, q_pe, ckv, kpe)
-    out = combine_partials(o_part, lse, axis=1)           # (B, nh, r)
+    out = combine_partials(o_part, lse[..., 0], axis=1)   # (B, nh, r)
     return out[:, :H].astype(q_lat.dtype)

@@ -13,6 +13,8 @@ scratch across the sequential chunk grid dimension, and all heavy ops are
 MXU matmuls.
 
 Layout: r,k,v,logw (BH, T, hd); u (BH, hd); state0 (BH, hd, hd).
+``u`` enters the kernel as (BH, 1, hd) so its block (1, 1, hd) spans
+the array's last two dimensions, as the TPU lowering requires.
 Grid (BH, T/C): chunk index minor/sequential.
 
 TPU sizing: hd = 64 (Finch), chunk C = 128: decay tensor (C, C, hd) f32 is
@@ -42,7 +44,7 @@ def _kernel(r_ref, k_ref, v_ref, w_ref, u_ref, s0_ref, y_ref, sout_ref,
     k = k_ref[0].astype(jnp.float32)
     v = v_ref[0].astype(jnp.float32)
     logw = w_ref[0].astype(jnp.float32)                   # (C, hd), <= 0
-    u = u_ref[0].astype(jnp.float32)                      # (hd,)
+    u = u_ref[0, 0].astype(jnp.float32)                   # (hd,)
     s = s_ref[...]                                        # (hd, hd)
     C, hd = r.shape
 
@@ -99,7 +101,7 @@ def rwkv6_wkv(r, k, v, logw, u, state0, *, chunk: int = 128,
             pl.BlockSpec((1, chunk, hd), lambda b, c: (b, c, 0)),
             pl.BlockSpec((1, chunk, hd), lambda b, c: (b, c, 0)),
             pl.BlockSpec((1, chunk, hd), lambda b, c: (b, c, 0)),
-            pl.BlockSpec((1, hd), lambda b, c: (b, 0)),
+            pl.BlockSpec((1, 1, hd), lambda b, c: (b, 0, 0)),
             pl.BlockSpec((1, hd, hd), lambda b, c: (b, 0, 0)),
         ],
         out_specs=[
@@ -112,5 +114,5 @@ def rwkv6_wkv(r, k, v, logw, u, state0, *, chunk: int = 128,
         ],
         scratch_shapes=[pltpu.VMEM((hd, hd), jnp.float32)],
         interpret=interpret,
-    )(r, k, v, logw, u, state0)
+    )(r, k, v, logw, u.reshape(BH, 1, hd), state0)
     return y[:, :T], sout

@@ -15,6 +15,12 @@ Layout:
 Grid (nt, nv): vocab is the minor (fastest) dimension; VMEM scratch
 carries the running max ``m``, running sum ``l`` and the gold logit
 across vocab tiles; the final tile writes ``log(l) + m - gold``.
+Per-token vectors travel as (T, 1) columns: Mosaic tiles a 1-D block
+differently from XLA, so a (bt,) block is refused on the TPU.
+
+A vocabulary that is not a multiple of ``bv`` (GPT-2's 50257 is odd)
+is zero-padded to one, and the padded columns are masked to -inf in
+the last tile, so they add nothing to the logsumexp.
 
 TPU sizing: tiles default to (bt, bv) = (256, 512); VMEM live set is
 hidden tile (bt, D) + emb tile (bv, D) + logits tile (bt, bv), i.e.
@@ -24,7 +30,6 @@ is the (bt, D) x (D, bv) matmul with all dims 128-aligned.
 from __future__ import annotations
 
 import functools
-from typing import Tuple
 
 import jax
 import jax.numpy as jnp
@@ -34,7 +39,8 @@ from jax.experimental.pallas import tpu as pltpu
 NEG_INF = -1e30
 
 
-def _kernel(h_ref, e_ref, lab_ref, nll_ref, m_ref, l_ref, gold_ref):
+def _kernel(h_ref, e_ref, lab_ref, nll_ref, m_ref, l_ref, gold_ref, *,
+            vocab):
     iv = pl.program_id(1)
     nv = pl.num_programs(1)
 
@@ -51,24 +57,24 @@ def _kernel(h_ref, e_ref, lab_ref, nll_ref, m_ref, l_ref, gold_ref):
         preferred_element_type=jnp.float32,
     )                                                     # (bt, bv)
     bt, bv = logits.shape
+    v0 = iv * bv
+    cols = jax.lax.broadcasted_iota(jnp.int32, (bt, bv), 1)
+    if vocab % bv:                     # mask the zero-padded vocab tail
+        logits = jnp.where(v0 + cols < vocab, logits, NEG_INF)
 
     # online logsumexp
-    m_prev = m_ref[...]
-    m_new = jnp.maximum(m_prev, logits.max(axis=-1))
+    m_prev = m_ref[...]                                   # (bt, 1)
+    m_new = jnp.maximum(m_prev, logits.max(axis=-1, keepdims=True))
     alpha = jnp.exp(m_prev - m_new)
-    l_ref[...] = l_ref[...] * alpha + jnp.exp(
-        logits - m_new[:, None]
-    ).sum(axis=-1)
+    l_ref[...] = l_ref[...] * alpha + jnp.exp(logits - m_new).sum(
+        axis=-1, keepdims=True)
     m_ref[...] = m_new
 
     # gold logit if it falls inside this vocab tile
-    labels = lab_ref[...]                                 # (bt,) i32 global ids
-    v0 = iv * bv
-    local = labels - v0
-    in_tile = (local >= 0) & (local < bv)
-    cols = jax.lax.broadcasted_iota(jnp.int32, (bt, bv), 1)
-    hit = cols == jnp.where(in_tile, local, -1)[:, None]
-    gold_ref[...] = gold_ref[...] + jnp.where(hit, logits, 0.0).sum(axis=-1)
+    local = lab_ref[...] - v0                             # (bt, 1) i32
+    hit = cols == local
+    gold_ref[...] = gold_ref[...] + jnp.where(hit, logits, 0.0).sum(
+        axis=-1, keepdims=True)
 
     @pl.when(iv == nv - 1)
     def _finish():
@@ -82,37 +88,33 @@ def score_ce(hidden: jax.Array, emb: jax.Array, labels: jax.Array, *,
              interpret: bool = False) -> jax.Array:
     """Per-token NLL (T,) f32 of ``softmax(hidden @ emb.T)`` at ``labels``.
 
-    Pads T and V up to tile multiples (padded vocab rows are -inf-free
-    because emb padding contributes exp(logit)=exp(0·h)=1 — so V padding
-    uses a -inf additive trick instead: padded vocab columns are masked by
-    the hit/max math operating on real tiles only; we pad emb with zeros
-    and subtract their contribution by masking in-kernel via tile bounds.
-    For simplicity, V must be a multiple of bv and T is padded here.)
-    """
+    T is zero-padded to a multiple of ``bt`` and V to a multiple of
+    ``bv``; the padded vocab columns are masked inside the kernel."""
     T, D = hidden.shape
     V = emb.shape[0]
-    assert V % bv == 0, f"V={V} must divide bv={bv} (pad the vocab)"
-    tpad = (-T) % bt
+    tpad, vpad = (-T) % bt, (-V) % bv
     if tpad:
         hidden = jnp.pad(hidden, ((0, tpad), (0, 0)))
         labels = jnp.pad(labels, ((0, tpad),))
+    if vpad:
+        emb = jnp.pad(emb, ((0, vpad), (0, 0)))
     Tp = T + tpad
-    grid = (Tp // bt, V // bv)
+    grid = (Tp // bt, (V + vpad) // bv)
     nll = pl.pallas_call(
-        _kernel,
+        functools.partial(_kernel, vocab=V),
         grid=grid,
         in_specs=[
             pl.BlockSpec((bt, D), lambda it, iv: (it, 0)),
             pl.BlockSpec((bv, D), lambda it, iv: (iv, 0)),
-            pl.BlockSpec((bt,), lambda it, iv: (it,)),
+            pl.BlockSpec((bt, 1), lambda it, iv: (it, 0)),
         ],
-        out_specs=pl.BlockSpec((bt,), lambda it, iv: (it,)),
-        out_shape=jax.ShapeDtypeStruct((Tp,), jnp.float32),
+        out_specs=pl.BlockSpec((bt, 1), lambda it, iv: (it, 0)),
+        out_shape=jax.ShapeDtypeStruct((Tp, 1), jnp.float32),
         scratch_shapes=[
-            pltpu.VMEM((bt,), jnp.float32),    # running max m
-            pltpu.VMEM((bt,), jnp.float32),    # running sum l
-            pltpu.VMEM((bt,), jnp.float32),    # gold logit
+            pltpu.VMEM((bt, 1), jnp.float32),  # running max m
+            pltpu.VMEM((bt, 1), jnp.float32),  # running sum l
+            pltpu.VMEM((bt, 1), jnp.float32),  # gold logit
         ],
         interpret=interpret,
-    )(hidden, emb, labels)
-    return nll[:T]
+    )(hidden, emb, labels.astype(jnp.int32).reshape(Tp, 1))
+    return nll[:T, 0]

@@ -15,21 +15,29 @@ from typing import Optional, Tuple
 
 import jax
 import numpy as np
-from jax.sharding import Mesh, NamedSharding
+from jax.sharding import AxisType, Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
+
+
+def _auto_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]) -> Mesh:
+    """``jax.make_mesh`` with GSPMD-propagated (``Auto``) axes. Its default
+    is ``Explicit`` axes, under which gathers and reshapes of sharded
+    operands demand an output sharding that this code leaves to the
+    partitioner."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_debug_mesh(data: int = 1, model: int = 1) -> Mesh:
     """Tiny mesh over however many real devices exist (CPU testing)."""
     n = len(jax.devices())
     assert data * model <= n, f"need {data * model} devices, have {n}"
-    return jax.make_mesh((data, model), ("data", "model"))
+    return _auto_mesh((data, model), ("data", "model"))
 
 
 def data_axes(mesh: Mesh) -> Tuple[str, ...]:

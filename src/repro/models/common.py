@@ -238,9 +238,11 @@ def embed_tokens(p, tokens: jax.Array, dtype: str) -> jax.Array:
 
 def unembed(cfg: ModelConfig, p, h: jax.Array) -> jax.Array:
     if cfg.tie_embeddings:
-        logits = jnp.einsum("...d,vd->...v", h, p["embedding"]).astype(jnp.float32)
+        logits = jnp.einsum("...d,vd->...v", h, p["embedding"],
+                            preferred_element_type=jnp.float32)
     else:
-        logits = (h @ p["unembed"]).astype(jnp.float32)
+        logits = jnp.matmul(h, p["unembed"],
+                            preferred_element_type=jnp.float32)
     if cfg.logit_soft_cap > 0:
         c = cfg.logit_soft_cap
         logits = c * jnp.tanh(logits / c)

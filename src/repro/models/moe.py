@@ -21,15 +21,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-# jax >= 0.6 promotes shard_map to jax.shard_map (check_vma kwarg);
-# earlier releases have it under jax.experimental with check_rep.
-if hasattr(jax, "shard_map"):
-    _shard_map = jax.shard_map
-    _SHARD_MAP_NOCHECK = {"check_vma": False}
-else:                                            # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _shard_map
-    _SHARD_MAP_NOCHECK = {"check_rep": False}
-
 from repro.config import ModelConfig
 from repro.models.common import (
     ParamSpec,
@@ -221,7 +212,7 @@ def _moe_ffn_expert_parallel(cfg: ModelConfig, p: Dict, x: jax.Array,
         aux = jax.lax.pmean(aux, data_axes)
         return y.reshape(Bl, Sl, D), aux
 
-    y, aux = _shard_map(
+    y, aux = jax.shard_map(
         local_fn,
         mesh=mesh,
         in_specs=(
@@ -232,7 +223,7 @@ def _moe_ffn_expert_parallel(cfg: ModelConfig, p: Dict, x: jax.Array,
             P("data", "model", None),               # w_down
         ),
         out_specs=(P(batch_entry, None, None), P()),
-        **_SHARD_MAP_NOCHECK,
+        check_vma=False,
     )(x, p["router"], p["w_gate"], p["w_up"], p["w_down"])
     if m.num_shared_experts > 0:
         y = y + apply_ffn(cfg, p["shared"], x.reshape(B * S, D)).reshape(

@@ -337,10 +337,12 @@ class Model:
             fe = (frontend @ params["frontend_proj"]).astype(x.dtype)
             parts.append(fe)
         if prompt is not None:
-            pe = prompt.astype(x.dtype)
+            # broadcast before the cast: the prompt gradient then sums
+            # over the batch in the prompt's own (f32) precision
+            pe = prompt
             if pe.ndim == 2:
                 pe = jnp.broadcast_to(pe[None], (B, *pe.shape))
-            parts.append(pe)
+            parts.append(pe.astype(x.dtype))
         parts.append(x)
         x = jnp.concatenate(parts, axis=1) if len(parts) > 1 else x
         positions = jnp.broadcast_to(jnp.arange(x.shape[1])[None], x.shape[:2])

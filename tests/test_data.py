@@ -96,3 +96,25 @@ def test_family_property_bounded_alphabet(family, param, seed):
     x = np.random.default_rng(seed).integers(0, 32, size=(3, 8))
     y = _apply_family(family, param, x, 32)
     assert ((y >= 0) & (y < 32)).all()
+
+
+def test_task_loader_batches_do_not_depend_on_the_process():
+    """Same seed, same task -> same batches in another interpreter, whose
+    str hashing is salted differently."""
+    import os
+    import subprocess
+    import sys
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    code = ("from repro.data import LoaderConfig, TaskLoader, make_tasks;"
+            "t = make_tasks(partitions=2)[7];"
+            "print(next(TaskLoader(t, LoaderConfig(batch_size=2)))"
+            "['tokens'].tolist())")
+    env = {**os.environ, "PYTHONPATH": os.path.join(root, "src"),
+           "JAX_PLATFORMS": "cpu", "PYTHONHASHSEED": "12345"}
+    r = subprocess.run([sys.executable, "-c", code], env=env, cwd=root,
+                       capture_output=True, text=True, timeout=120)
+    assert r.returncode == 0, r.stderr[-2000:]
+    here = next(TaskLoader(make_tasks(partitions=2)[7],
+                           LoaderConfig(batch_size=2)))["tokens"].tolist()
+    assert r.stdout.strip() == str(here)

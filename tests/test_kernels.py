@@ -33,6 +33,8 @@ from repro.kernels.score_ce import score_ce
     (100, 128, 1024, 32, 256),       # T not a tile multiple
     (17, 32, 256, 16, 256),          # single vocab tile
     (256, 256, 2048, 128, 512),
+    (40, 32, 300, 8, 128),           # V not a tile multiple: masked tail
+    (24, 64, 517, 8, 256),           # odd V, like GPT-2's 50257
 ])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_score_ce_sweep(T, D, V, bt, bv, dtype):
@@ -63,6 +65,49 @@ def test_fused_score_ce_matches_naive(pre_base):
     m2, p2 = token_cross_entropy(logits, b["labels"], b["mask"])
     np.testing.assert_allclose(float(mean), float(m2), rtol=1e-5)
     np.testing.assert_allclose(np.asarray(per), np.asarray(p2), rtol=1e-5)
+
+
+def test_fused_score_ce_odd_vocab_keeps_the_tile(monkeypatch):
+    """An odd vocabulary is padded and masked, never turned into one
+    whole-vocabulary tile."""
+    from repro.kernels import ops
+    from repro.train.objectives import token_cross_entropy
+
+    tiles = []
+
+    def spy(*args, **kw):
+        tiles.append(kw["bv"])
+        return score_ce(*args, **kw)
+
+    monkeypatch.setattr(ops, "score_ce", spy)
+    key = jax.random.key(21)
+    B, S, d, V = 2, 12, 32, 1031
+    hidden = jax.random.normal(key, (B, S, d))
+    emb = jax.random.normal(jax.random.fold_in(key, 1), (V, d)) * 0.1
+    labels = jax.random.randint(jax.random.fold_in(key, 2), (B, S), 0, V)
+    labels = labels.at[0, 0].set(V - 1)          # gold in the last tile
+    mask = jnp.ones((B, S)).at[1, :3].set(0.0)
+    mean, per = ops.fused_score_ce(hidden, emb, labels, mask, bv=256)
+    assert tiles == [256]
+    m2, p2 = token_cross_entropy(hidden @ emb.T, labels, mask)
+    np.testing.assert_allclose(float(mean), float(m2), rtol=1e-5)
+    np.testing.assert_allclose(np.asarray(per), np.asarray(p2), rtol=1e-5)
+
+
+@pytest.mark.parametrize("backend,expected", [("cpu", True), ("tpu", False)])
+def test_kernels_interpret_only_on_cpu(monkeypatch, backend, expected):
+    from repro.kernels import ops
+
+    monkeypatch.setattr(ops.jax, "default_backend", lambda: backend)
+    assert ops._interpret() is expected
+
+
+def test_kernels_refuse_other_backends(monkeypatch):
+    from repro.kernels import ops
+
+    monkeypatch.setattr(ops.jax, "default_backend", lambda: "gpu")
+    with pytest.raises(RuntimeError, match="'gpu'"):
+        ops._interpret()
 
 
 # -- flash attention -----------------------------------------------------------
