@@ -59,6 +59,7 @@ from repro.core.jobs import (
 )
 from repro.core.prompt_bank import PromptBank, PromptEntry
 from repro.obs import Telemetry
+from repro.obs.device import span
 
 from repro.api.types import JobHandle, JobResult, SubmitRequest
 
@@ -178,27 +179,29 @@ class PromptTunerService:
         effective_slo = float(req.slo) * cls.slo_multiplier
         submitted_at = (self.fabric.now if req.submit_time is None
                         else float(req.submit_time))
-        routed = self.route_through_bank(req)
-        origin = score = init_prompt = None
-        if routed and self.bank is not None and self.score_fn_factory is not None:
-            lookup = self.bank.lookup(self.score_fn_factory(req))
-            origin, score = lookup.entry.origin, lookup.score
-            init_prompt = lookup.entry.prompt
         job_id = self._next_id
         self._next_id += 1
-        job = Job(
-            job_id=job_id,
-            llm=req.llm,
-            submit_time=submitted_at,
-            slo=effective_slo,
-            iters_manual=req.iters_manual,
-            iters_bank=req.iters_bank,
-            max_iters=req.max_iters,
-            task_id=req.task_id,
-            tenant=req.tenant,
-            slo_class=cls,
-        )
-        shard = self.fabric.submit(job)
+        with span("service.submit", job=job_id):
+            routed = self.route_through_bank(req)
+            origin = score = init_prompt = None
+            if (routed and self.bank is not None
+                    and self.score_fn_factory is not None):
+                lookup = self.bank.lookup(self.score_fn_factory(req))
+                origin, score = lookup.entry.origin, lookup.score
+                init_prompt = lookup.entry.prompt
+            job = Job(
+                job_id=job_id,
+                llm=req.llm,
+                submit_time=submitted_at,
+                slo=effective_slo,
+                iters_manual=req.iters_manual,
+                iters_bank=req.iters_bank,
+                max_iters=req.max_iters,
+                task_id=req.task_id,
+                tenant=req.tenant,
+                slo_class=cls,
+            )
+            shard = self.fabric.submit(job)
         rejected = shard < 0
         reason = self.fabric.rejections[-1][1] if rejected else None
         handle = JobHandle(

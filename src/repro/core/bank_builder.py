@@ -27,6 +27,7 @@ from repro.config import TuneConfig
 from repro.core.prompt_bank import PromptBank, PromptEntry
 from repro.data import LoaderConfig, TaskLoader, TaskSpec, batch_to_jnp
 from repro.models import Model
+from repro.obs.device import span
 from repro.train.pretrain import PretrainResult
 from repro.tuning import PromptTuner, activation_features
 
@@ -78,7 +79,8 @@ class ScoreContext:
     eval_batch: Dict
 
     def __call__(self, entry: PromptEntry) -> float:
-        pp = {"soft_prompt": jnp.asarray(entry.prompt)}
+        with span("tuner.upload"):
+            pp = {"soft_prompt": jnp.asarray(entry.prompt)}
         return self.tuner.score(pp, self.params, self.eval_batch)
 
 

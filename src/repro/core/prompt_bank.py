@@ -13,11 +13,12 @@ closest to its medoid (max diversity) once capacity is exceeded.
 from __future__ import annotations
 
 import dataclasses
-import time
 from dataclasses import dataclass, field
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
+
+from repro.obs.device import span
 
 
 # ---------------------------------------------------------------------------
@@ -90,7 +91,6 @@ class LookupResult:
     entry: PromptEntry
     score: float
     evaluations: int              # number of Eqn-1 evaluations performed
-    latency_s: float
     cluster: int
 
 
@@ -124,9 +124,8 @@ class PromptBank:
         self.entries.extend(entries)
         self._built = False
 
-    def build(self) -> float:
-        """(Re-)cluster all candidates. Returns build time in seconds."""
-        t0 = time.time()
+    def build(self) -> None:
+        """(Re-)cluster all candidates."""
         if not self.entries:
             raise ValueError("empty bank")
         feats = np.stack([e.feature for e in self.entries])
@@ -137,7 +136,6 @@ class PromptBank:
             [int(i) for i in np.where(assign == ci)[0]] for ci in range(len(medoids))
         ]
         self._built = True
-        return time.time() - t0
 
     def __len__(self) -> int:
         return sum(1 for e in self.entries if e.origin != "<evicted>")
@@ -148,42 +146,45 @@ class PromptBank:
         """Two-layer lookup: score K medoids, then members of the best
         cluster; K + C/K evaluations total."""
         assert self._built, "call build() first"
-        t0 = time.time()
-        evals = 0
-        best_ci, best_medoid_score = 0, float("inf")
-        for ci, mid in enumerate(self.medoid_ids):
-            s = score_fn(self.entries[mid])
-            evals += 1
-            if s < best_medoid_score:
-                best_medoid_score, best_ci = s, ci
-        best_idx, best_score = self.medoid_ids[best_ci], best_medoid_score
-        for idx in self.clusters[best_ci]:
-            if idx == self.medoid_ids[best_ci]:
-                continue
-            if self.entries[idx].origin == "<evicted>":
-                continue
-            s = score_fn(self.entries[idx])
-            evals += 1
-            if s < best_score:
-                best_score, best_idx = s, idx
+        with span("bank.lookup"):
+            evals = 0
+            best_ci, best_medoid_score = 0, float("inf")
+            for ci, mid in enumerate(self.medoid_ids):
+                with span("bank.score", layer=1):
+                    s = score_fn(self.entries[mid])
+                evals += 1
+                if s < best_medoid_score:
+                    best_medoid_score, best_ci = s, ci
+            best_idx, best_score = self.medoid_ids[best_ci], best_medoid_score
+            for idx in self.clusters[best_ci]:
+                if idx == self.medoid_ids[best_ci]:
+                    continue
+                if self.entries[idx].origin == "<evicted>":
+                    continue
+                with span("bank.score", layer=2):
+                    s = score_fn(self.entries[idx])
+                evals += 1
+                if s < best_score:
+                    best_score, best_idx = s, idx
         return LookupResult(
             entry=self.entries[best_idx],
             score=best_score,
             evaluations=evals,
-            latency_s=time.time() - t0,
             cluster=best_ci,
         )
 
     def lookup_flat(self, score_fn) -> LookupResult:
         """Brute force over all C candidates (the K=1 baseline of Fig 10b)."""
-        t0 = time.time()
-        scores = [score_fn(e) for e in self.entries]
+        scores = []
+        with span("bank.lookup"):
+            for e in self.entries:
+                with span("bank.score"):
+                    scores.append(score_fn(e))
         i = int(np.argmin(scores))
         return LookupResult(
             entry=self.entries[i],
             score=float(scores[i]),
             evaluations=len(scores),
-            latency_s=time.time() - t0,
             cluster=-1,
         )
 

@@ -256,6 +256,13 @@ class MetricsRegistry:
         return {format_series(n, lk): inst.read()
                 for (n, lk), inst in sorted(self._instruments.items())}
 
+    def instruments(self, name: str) -> List[Tuple[Dict[str, str], object]]:
+        """``(labels, instrument)`` of every series of ``name``, sorted."""
+        return [(dict(lk), inst)
+                for (n, lk), inst in sorted(self._instruments.items(),
+                                            key=lambda kv: kv[0])
+                if n == name]
+
     def value(self, name: str, **labels) -> float:
         """Convenience scalar read: counter/gauge value (0 when the
         series does not exist)."""

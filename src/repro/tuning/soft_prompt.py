@@ -23,6 +23,7 @@ import numpy as np
 from repro.config import ModelConfig, TuneConfig
 from repro.data import TaskLoader, batch_to_jnp
 from repro.models import Model
+from repro.obs.device import span
 from repro.train import apply_updates, lpt_loss, make_optimizer
 
 
@@ -94,12 +95,19 @@ class PromptTuner:
         return self.optimizer.init(prompt_params)
 
     def step(self, prompt_params, opt_state, params, batch):
-        return self._step(prompt_params, opt_state, params, batch_to_jnp(batch))
+        with span("tuner.upload"):
+            batch = batch_to_jnp(batch)
+        with span("tuner.dispatch"):
+            return self._step(prompt_params, opt_state, params, batch)
 
     def score(self, prompt_params, params, eval_batch) -> float:
         """Eqn 1: mean loss on D_eval, no tuning. Smaller is better."""
-        tot, (loss, _) = self._score(prompt_params, params, batch_to_jnp(eval_batch))
-        return float(loss)
+        with span("tuner.upload"):
+            eval_batch = batch_to_jnp(eval_batch)
+        with span("tuner.dispatch"):
+            tot, (loss, _) = self._score(prompt_params, params, eval_batch)
+        with span("tuner.sync"):
+            return float(loss)
 
     def evaluate(self, prompt_params, params, eval_batch) -> float:
         return self.score(prompt_params, params, eval_batch)
@@ -121,30 +129,37 @@ class PromptTuner:
         Returns {prompt, iters, reached, history}."""
         max_iters = max_iters or self.tune_cfg.max_iters
         eval_every = eval_every or self.tune_cfg.eval_every
-        eval_batch = loader.eval_batch(self.tune_cfg.eval_samples)
-        opt_state = self.init_opt(prompt_params)
-        history = []
-        reached = False
-        it = 0
-        # the initial prompt may already meet the target (ITA = 0) — the
-        # whole point of prompt reusing
-        if target_loss is not None:
-            ev0 = self.score(prompt_params, params, eval_batch)
-            history.append((0, float("nan"), ev0))
-            if ev0 <= target_loss:
-                return {"prompt": prompt_params, "iters": 0,
-                        "reached": True, "history": history}
-        for it in range(1, max_iters + 1):
-            batch = next(loader)
-            prompt_params, opt_state, loss = self.step(
-                prompt_params, opt_state, params, batch
-            )
-            if it % eval_every == 0:
-                ev = self.score(prompt_params, params, eval_batch)
-                history.append((it, float(loss), ev))
-                if target_loss is not None and ev <= target_loss:
-                    reached = True
-                    break
+        with span("tune.job"):
+            eval_batch = loader.eval_batch(self.tune_cfg.eval_samples)
+            opt_state = self.init_opt(prompt_params)
+            history = []
+            reached = False
+            it = 0
+            # the initial prompt may already meet the target (ITA = 0) —
+            # the whole point of prompt reusing
+            if target_loss is not None:
+                with span("tune.eval"):
+                    ev0 = self.score(prompt_params, params, eval_batch)
+                history.append((0, float("nan"), ev0))
+                if ev0 <= target_loss:
+                    return {"prompt": prompt_params, "iters": 0,
+                            "reached": True, "history": history}
+            for it in range(1, max_iters + 1):
+                with span("tune.batch"):
+                    batch = next(loader)
+                with span("tune.step"):
+                    prompt_params, opt_state, loss = self.step(
+                        prompt_params, opt_state, params, batch
+                    )
+                if it % eval_every == 0:
+                    with span("tune.eval"):
+                        ev = self.score(prompt_params, params, eval_batch)
+                    with span("tuner.sync"):
+                        loss = float(loss)
+                    history.append((it, loss, ev))
+                    if target_loss is not None and ev <= target_loss:
+                        reached = True
+                        break
         return {
             "prompt": prompt_params,
             "iters": it,
