@@ -326,12 +326,11 @@ def phase_data_parallel(cfg: ModelConfig, *, n_data: int, global_batch: int,
     model = build_model(cfg, model_axis=1, data_axis=n_data, mesh=mesh)
     specs = input_specs(model, shape, tune_cfg)
     sh = step_shardings(model, shape, mesh, specs)
-    fn, opt = make_train_step(model, tune_cfg, batch_axes=data_axes(mesh))
-    dp_step = jax.jit(fn, in_shardings=(sh["params"], sh["prompt_params"],
-                                        sh["opt_state"], sh["batch"]))
+    # both steps take arrays placed as ``sh`` says (or on one device)
+    dp_step, opt = make_train_step(model, tune_cfg,
+                                   batch_axes=data_axes(mesh))
     one_model = build_model(cfg)
-    one_fn, _ = make_train_step(one_model, tune_cfg, microbatches=n_data)
-    one_step = jax.jit(one_fn)
+    one_step, _ = make_train_step(one_model, tune_cfg, microbatches=n_data)
 
     params = one_model.init(jax.random.key(SEED))
     dp_params = jax.device_put(params, sh["params"])

@@ -54,7 +54,6 @@ def main():
     loader = TaskLoader(task, LoaderConfig(batch_size=args.batch))
     tune_cfg = TuneConfig(prompt_len=16, lr=0.3, batch_size=args.batch)
     step, opt = make_train_step(model, tune_cfg)
-    step = jax.jit(step)
 
     key = jax.random.key(1)
     prompt = {"soft_prompt": jax.random.normal(

@@ -64,12 +64,13 @@ def dryrun_one(arch: str, shape_name: str, *, multi_pod: bool = False,
     fn, specs, shardings, model = build_step(
         cfg, shape_name, mesh, microbatches=microbatches, ce_chunk=ce_chunk
     )
+    args = [jax.tree.map(
+        lambda s, sh: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sh),
+        specs[k], shardings[k]) for k in specs]
     with mesh:
-        jitted = jax.jit(
-            fn,
-            in_shardings=tuple(shardings[k] for k in specs),
-        )
-        lowered = jitted.lower(*(specs[k] for k in specs))
+        # a train step compiles its save rungs here, so for it lower_s
+        # holds the compile and compile_s is ~0
+        lowered = fn.lower(*args)
         t_lower = time.time() - t0
         compiled = lowered.compile()
         t_compile = time.time() - t0 - t_lower

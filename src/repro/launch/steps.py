@@ -31,6 +31,7 @@ from repro.launch import mesh as mesh_lib
 from repro.models import Model, build_model
 from repro.train.objectives import lpt_loss_chunked
 from repro.train.optimizer import adam, apply_updates
+from repro.train.remat import GradProgram
 
 # Sub-quadratic long-context policy (DESIGN.md §5): dense full-attention
 # archs run long_500k with a sliding-window cache variant.
@@ -70,12 +71,25 @@ def make_train_step(model: Model, tune_cfg: TuneConfig, *,
     """(params, prompt_params, opt_state, batch) ->
     (prompt_params, opt_state, loss). Grads w.r.t. the prompt only.
 
+    The step is a :class:`~repro.train.remat.GradProgram`: call it (or
+    its ``lower``) with placed arrays or sharded shape structs, and it
+    compiles on the first save rung that fits the devices; inside
+    another jit it traces full rematerialisation.
+
     ``batch_axes``: mesh axes the per-microbatch batch dim must stay
     sharded over (the reshape to (m, B/m, ...) would otherwise let GSPMD
     move the sharding onto the scan axis, silently un-sharding each
     microbatch)."""
     opt = adam(tune_cfg.lr, weight_decay=tune_cfg.weight_decay)
+    key = ("make_train_step", tune_cfg, microbatches, ce_chunk,
+           tuple(batch_axes))
+    return GradProgram(
+        lambda m: _train_step(m, opt, microbatches, ce_chunk, batch_axes),
+        model, key), opt
 
+
+def _train_step(model: Model, opt, microbatches: int, ce_chunk: int,
+                batch_axes: Tuple[str, ...]):
     def loss_fn(prompt_params, params, batch):
         tot, (loss, _) = lpt_loss_chunked(
             model, params, prompt_params["soft_prompt"], batch, chunk=ce_chunk
@@ -122,7 +136,7 @@ def make_train_step(model: Model, tune_cfg: TuneConfig, *,
         new_prompt = apply_updates(prompt_params, updates)
         return new_prompt, new_opt, loss
 
-    return train_step, opt
+    return train_step
 
 
 def make_prefill_step(model: Model, *, ce_chunk: int = 512):
@@ -258,7 +272,9 @@ def step_shardings(model: Model, shape: InputShape, mesh: Mesh,
 def build_step(arch_cfg: ModelConfig, shape_name: str, mesh: Mesh, *,
                tune_cfg: Optional[TuneConfig] = None,
                microbatches: int = 1, ce_chunk: int = 512):
-    """Assemble (step_fn, specs, shardings, model) for one (arch, shape)."""
+    """Assemble (step, specs, shardings, model) for one (arch, shape).
+    ``step`` is jitted (a train step is a ``GradProgram``): lower it with
+    the specs placed as ``shardings`` says."""
     shape = INPUT_SHAPES[shape_name]
     tune_cfg = tune_cfg or TuneConfig()
     cfg = model_for_shape(arch_cfg, shape)
@@ -272,7 +288,7 @@ def build_step(arch_cfg: ModelConfig, shape_name: str, mesh: Mesh, *,
                                 ce_chunk=ce_chunk,
                                 batch_axes=mesh_lib.data_axes(mesh))
     elif shape.kind == "prefill":
-        fn = make_prefill_step(model, ce_chunk=ce_chunk)
+        fn = jax.jit(make_prefill_step(model, ce_chunk=ce_chunk))
     else:
-        fn = make_serve_step(model)
+        fn = jax.jit(make_serve_step(model))
     return fn, specs, shardings, model

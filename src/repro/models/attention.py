@@ -10,6 +10,7 @@ from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import PartitionSpec as P
 
 from repro.config import ModelConfig
@@ -154,9 +155,10 @@ def _qkv(cfg: ModelConfig, p, x, positions):
     v = jnp.einsum("bsd,dhk->bshk", x, p["wv"])
     if cfg.qkv_bias:
         q, k, v = q + p["bq"], k + p["bk"], v + p["bv"]
-    q = apply_rope(q, positions, cfg.rope_theta)
-    k = apply_rope(k, positions, cfg.rope_theta)
-    return q, k, v
+    # names a checkpoint policy may keep (``Model.saving``)
+    q = checkpoint_name(apply_rope(q, positions, cfg.rope_theta), "q")
+    k = checkpoint_name(apply_rope(k, positions, cfg.rope_theta), "k")
+    return q, k, checkpoint_name(v, "v")
 
 
 def gqa_forward(

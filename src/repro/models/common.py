@@ -16,6 +16,7 @@ from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import PartitionSpec as P
 
 from repro.config import ModelConfig
@@ -216,9 +217,11 @@ def ffn_params(cfg: ModelConfig, d_model: int, d_ff: int, model_axis: int):
 
 
 def apply_ffn(cfg: ModelConfig, p, x: jax.Array) -> jax.Array:
-    up = x @ p["w_up"]
+    # "up" and "gate": outputs a checkpoint policy may keep for the
+    # backward pass (``Model.saving``); no-ops outside one
+    up = checkpoint_name(x @ p["w_up"], "up")
     if cfg.activation == "swiglu":
-        act = jax.nn.silu(x @ p["w_gate"]) * up
+        act = jax.nn.silu(checkpoint_name(x @ p["w_gate"], "gate")) * up
     else:
         act = jax.nn.gelu(up)
     return act @ p["w_down"]

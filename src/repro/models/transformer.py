@@ -21,6 +21,7 @@ Public API (all pure functions; ``Model`` is a thin namespace):
 """
 from __future__ import annotations
 
+import copy
 import dataclasses
 from dataclasses import dataclass
 from typing import Any, Dict, List, Optional, Tuple
@@ -283,6 +284,26 @@ class Model:
         self.mesh = mesh            # enables shard_map expert parallelism
         self.segments = plan_segments(cfg)
         self.param_specs = self._build_param_specs()
+        self.saved: Tuple[str, ...] = ()    # see saving()
+
+    def saving(self, names: Tuple[str, ...]) -> "Model":
+        """This model with its checkpointed layer scans keeping the
+        outputs tagged ``names`` (``checkpoint_name``: "q", "k", "v" in
+        attention, "gate", "up" in the FFN) for the backward pass instead
+        of recomputing them. ``()`` keeps nothing: the backward pass
+        recomputes each layer's forward. Forward-only programs do not
+        change. ``repro.train.remat`` chooses the names."""
+        model = copy.copy(self)
+        model.saved = tuple(names)
+        return model
+
+    def _remat(self, body):
+        if not self.cfg.remat:
+            return body
+        if not self.saved:
+            return jax.checkpoint(body)
+        return jax.checkpoint(body, policy=jax.checkpoint_policies
+                              .save_only_these_names(*self.saved))
 
     # -- parameters ---------------------------------------------------------
 
@@ -383,7 +404,7 @@ class Model:
             h, _, _ = block_forward(cfg, "encoder", lp, h, positions, {})
             return h, None
 
-        fn = jax.checkpoint(body) if cfg.remat else body
+        fn = self._remat(body)
         x, _ = jax.lax.scan(fn, x, enc["blocks"])
         return apply_norm(cfg, enc["final_norm"], x)
 
@@ -415,7 +436,7 @@ class Model:
                     h, a, _ = block_forward(cfg, seg.kind, lp, h, positions, ctx2)
                     return (h, aux + a), None
 
-                fn = jax.checkpoint(body) if cfg.remat else body
+                fn = self._remat(body)
                 (x, aux_total), _ = jax.lax.scan(fn, (x, aux_total), stacked)
             elif seg.kind in ("rwkv", "mamba"):
                 def body(carry, lp):
@@ -424,7 +445,7 @@ class Model:
                                             {"state": None})
                     return (h, aux + a), None
 
-                fn = jax.checkpoint(body) if cfg.remat else body
+                fn = self._remat(body)
                 (x, aux_total), _ = jax.lax.scan(fn, (x, aux_total), stacked)
             else:
                 def body(carry, lp):
@@ -432,7 +453,7 @@ class Model:
                     h, a, _ = block_forward(cfg, seg.kind, lp, h, positions, ctx)
                     return (h, aux + a), None
 
-                fn = jax.checkpoint(body) if cfg.remat else body
+                fn = self._remat(body)
                 (x, aux_total), _ = jax.lax.scan(fn, (x, aux_total), stacked)
             # Zamba2-style shared attention between SSM segments
             if (

@@ -24,6 +24,12 @@ lowering and backend-compile seconds (a persistent-cache load reports as
 a compile) and the persistent cache's hits and misses into ``jit.*``
 series labelled by the innermost open span and its root. Nested events
 (a jit traced inside another's trace) are counted once.
+
+Each gradient program compiled on the save ladder of
+:mod:`repro.train.remat` is counted under its rung (1 keeps the most
+outputs for the backward pass), with the bytes of its temporaries from
+the compile's ``memory_analysis()``: the outputs it keeps are part of
+them.
 """
 from __future__ import annotations
 
@@ -107,7 +113,10 @@ class DeviceTracer:
     * ``jit.trace_s``, ``jit.lower_s``, ``jit.compile_s`` ``{span, root}``
       — histograms of JAX's trace, lowering and compile-or-load seconds;
     * ``jit.cache_hits``, ``jit.cache_misses`` ``{span, root}`` —
-      persistent-cache counters.
+      persistent-cache counters;
+    * ``remat.temp_bytes{rung, span, root}`` — histogram of the
+      temporaries' bytes of each gradient program compiled on that rung
+      of the save ladder (its count is the number of programs).
 
     A parent or root that does not exist is labelled ``""``."""
 
@@ -129,10 +138,20 @@ class DeviceTracer:
             self._listen()
         return _Span(self, name, ids)
 
+    def grad_program(self, rung: int, temp_bytes: int) -> None:
+        """Count one gradient program compiled on ``rung`` of the save
+        ladder, with its temporaries' bytes, under the innermost open
+        span; nothing while no profiler session is on."""
+        if _enabled():
+            self.registry.histogram("remat.temp_bytes", rung=rung,
+                                    **self._where()).observe(temp_bytes)
+
     def snapshot(self) -> Dict[str, List[Dict]]:
         """``{"spans": [{span, parent, count, total_s, self_s}],
-        "jit": [{metric, span, root, count, seconds}]}`` since the last
-        :meth:`reset` (``seconds`` is 0 for the cache counters)."""
+        "jit": [{metric, span, root, count, seconds}],
+        "remat": [{rung, span, root, count, temp_bytes}]}`` since the
+        last :meth:`reset` (``seconds`` is 0 for the cache counters;
+        ``temp_bytes`` sums over the ``count`` programs)."""
         reg = self.registry
         self_s = {(lb["span"], lb["parent"]): c.value
                   for lb, c in reg.instruments("span_self_s")}
@@ -148,7 +167,10 @@ class DeviceTracer:
                 jit.append(dict(metric=metric, span=lb["span"],
                                 root=lb["root"], count=count,
                                 seconds=seconds))
-        return {"spans": spans, "jit": jit}
+        remat = [dict(rung=int(lb["rung"]), span=lb["span"], root=lb["root"],
+                      count=h.count, temp_bytes=h.sum)
+                 for lb, h in reg.instruments("remat.temp_bytes")]
+        return {"spans": spans, "jit": jit, "remat": remat}
 
     def reset(self) -> None:
         """Forget every recorded span and counter."""
@@ -206,6 +228,7 @@ class DeviceTracer:
 
 TRACER = DeviceTracer()
 span = TRACER.span
+grad_program = TRACER.grad_program
 snapshot = TRACER.snapshot
 reset = TRACER.reset
 

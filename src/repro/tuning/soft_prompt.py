@@ -25,6 +25,7 @@ from repro.data import TaskLoader, batch_to_jnp
 from repro.models import Model
 from repro.obs.device import span
 from repro.train import apply_updates, lpt_loss, make_optimizer
+from repro.train.remat import GradProgram
 
 
 def init_prompt_from_tokens(model: Model, params, token_ids: jax.Array):
@@ -51,24 +52,30 @@ class PromptTuner:
         self.optimizer = make_optimizer(
             self.tune_cfg.optimizer, self.tune_cfg.lr, self.tune_cfg.weight_decay
         )
-        model = self.model
+        self._score = jax.jit(self._loss_fn(self.model))
+        # the step keeps what the chip can hold for its backward pass
+        self._step = GradProgram(self._step_fn, self.model,
+                                 key=("PromptTuner.step", self.tune_cfg))
+
+    def _loss_fn(self, model: Model):
         P = self.tune_cfg.prompt_len
 
         def loss_fn(prompt_params, params, batch):
             prompt = self._materialize_prompt(prompt_params, params)
             return lpt_loss(model, params, prompt, batch, P)
 
-        self._loss = loss_fn
-        self._grad = jax.jit(jax.value_and_grad(loss_fn, has_aux=True))
-        self._score = jax.jit(loss_fn)
+        return loss_fn
+
+    def _step_fn(self, model: Model):
+        grad = jax.value_and_grad(self._loss_fn(model), has_aux=True)
 
         def step(prompt_params, opt_state, params, batch):
-            (tot, (loss, _)), grads = self._grad(prompt_params, params, batch)
+            (tot, (loss, _)), grads = grad(prompt_params, params, batch)
             updates, opt_state = self.optimizer.update(grads, opt_state, prompt_params)
             prompt_params = apply_updates(prompt_params, updates)
             return prompt_params, opt_state, loss
 
-        self._step = jax.jit(step)
+        return step
 
     # prefix variant: reparameterize the prompt through a small MLP
     def _materialize_prompt(self, prompt_params, params):

@@ -41,7 +41,7 @@ def test_forward_and_train_step(arch):
     step, opt = make_train_step(model, tc)
     pp = {"soft_prompt": jnp.zeros((4, cfg.d_model), jnp.float32)}
     opt_state = opt.init(pp)
-    pp2, opt_state2, loss = jax.jit(step)(params, pp, opt_state, batch)
+    pp2, opt_state2, loss = step(params, pp, opt_state, batch)
     assert jnp.isfinite(loss), arch
     assert pp2["soft_prompt"].shape == (4, cfg.d_model)
     # the step must actually move the prompt

@@ -58,7 +58,7 @@ def test_off_records_nothing_and_allocates_nothing():
     finally:
         tracemalloc.stop()
     assert after - before == 0
-    assert device.snapshot() == {"spans": [], "jit": []}
+    assert device.snapshot() == {"spans": [], "jit": [], "remat": []}
 
 
 def test_nested_spans_count_total_self_and_share_root_id(tmp_path):
@@ -163,6 +163,32 @@ def test_tune_spans_count_steps_and_evals(tmp_path):
     # the step and eval programs are traced and compiled inside the job
     assert device.total(snap["jit"], "count", metric="jit.compile_s",
                         root="tune.job") >= 2
+
+
+def test_tune_job_counts_its_step_program_on_a_rung(tmp_path):
+    cfg = smoke_config("qwen2-7b").with_overrides(
+        remat=True, dtype="bfloat16", param_dtype="bfloat16")
+    model = build_model(cfg)
+    params = model.init(jax.random.key(0))
+    tc = TuneConfig(prompt_len=4, batch_size=2, eval_every=2, eval_samples=2)
+    loader = TaskLoader(TaskSpec("shift", 1, cfg.vocab_size - 8),
+                        LoaderConfig(batch_size=2))
+    pp = {"soft_prompt": jnp.zeros((4, cfg.d_model), jnp.float32)}
+    device.reset()
+    PromptTuner(model, tc).tune(params, loader, pp, max_iters=3)  # untraced
+    assert device.snapshot()["remat"] == []
+    with tracing(tmp_path):
+        PromptTuner(model, tc).tune(params, loader, pp, max_iters=3)
+    row, = device.snapshot()["remat"]
+    # one step program, compiled on the first rung (the CPU has room)
+    assert (row["rung"], row["count"]) == (1, 1)
+    assert (row["span"], row["root"]) == ("tuner.dispatch", "tune.job")
+    # the five outputs of every layer, in bf16, are among them
+    B, S = 2, tc.prompt_len + next(loader)["tokens"].shape[1]
+    kept = cfg.num_layers * B * S * 2 * (
+        (cfg.num_heads + 2 * cfg.kv_heads()) * cfg.resolved_head_dim()
+        + 2 * cfg.d_ff)
+    assert row["temp_bytes"] >= kept
 
 
 def test_span_names_on_the_host_plane(tmp_path):

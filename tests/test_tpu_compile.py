@@ -6,6 +6,8 @@ The topology is described inside a fixture, never while a module is
 imported: only one process may load the TPU library, and under xdist
 every worker imports every test file. Keep these tests in this one file.
 """
+import re
+
 import numpy as np
 import pytest
 import jax
@@ -22,6 +24,7 @@ from repro.kernels.score_ce import score_ce
 from repro.launch.mesh import data_axes
 from repro.launch.steps import input_specs, make_train_step, step_shardings
 from repro.models import build_model
+from repro.train.remat import SAVE_LADDER
 from repro.tuning import PromptTuner
 
 HBM_BYTES = 16 * 2**30          # one v5e chip
@@ -163,6 +166,65 @@ def test_prompt_tuner_program_fits_one_chip(one_chip, program):
     assert used < HBM_BYTES, used
 
 
+def _step_args(model, tune_cfg, tuner, tokens, sharding):
+    pp = {"soft_prompt": _spec((tune_cfg.prompt_len, model.cfg.d_model),
+                               jnp.float32, sharding)}
+    batch = {k: _spec((tune_cfg.batch_size, tokens), d, sharding)
+             for k, d in (("tokens", jnp.int32), ("labels", jnp.int32),
+                          ("mask", jnp.float32))}
+    return (pp, _on(jax.eval_shape(tuner.init_opt, pp), sharding),
+            _on(model.abstract_params(), sharding), batch)
+
+
+def _rung(program):
+    """The save-ladder rung (0-based) a ``GradProgram`` compiled on."""
+    chosen, = program._chosen.values()
+    rung, = [r for r, j in program._rung_jits.items() if j is chosen]
+    return rung
+
+
+def _rematted_dots_of_width(text, width):
+    """Instructions of the compiled text that recompute a dot in the
+    backward pass with an output dimension ``width``."""
+    return [line for line in text.splitlines()
+            if "rematted_computation" in line and "dot_general" in line
+            and re.search(rf"[\[,]{width}\]", line.split("metadata=")[0])]
+
+
+def test_tuning_step_keeps_projections_at_qwen2_stage(one_chip):
+    """The ``qwen2.tune`` cell's step (one 14-layer stage of Qwen2-7B,
+    batch 8 x (16 + 257)): the first rung fits, so the backward pass reads
+    q, k, v, gate and up instead of recomputing them."""
+    cfg = get_config("qwen2-7b").with_overrides(num_layers=14)
+    model = build_model(cfg)
+    tune_cfg = TuneConfig(prompt_len=16, batch_size=8)
+    tuner = PromptTuner(model, tune_cfg)
+    compiled = tuner._step.lower(
+        *_step_args(model, tune_cfg, tuner, TOKENS, one_chip)).compile()
+    assert _rung(tuner._step) == 0
+    assert not _rematted_dots_of_width(compiled.as_text(), cfg.d_ff)
+    mem = compiled.memory_analysis()
+    assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            < HBM_BYTES)
+
+
+def test_tuning_step_steps_down_at_gpt2_large_long(one_chip):
+    """GPT2-Large at batch 16 x (16 + 1008): keeping all five outputs
+    runs out of the chip's memory, so the step compiles a rung lower, and
+    a fresh tuner (the next job) starts on that rung."""
+    model, _, _ = _gpt2_large_tuner()
+    tune_cfg = TuneConfig(prompt_len=16, batch_size=16)
+    tuner = PromptTuner(model, tune_cfg)
+    args = _step_args(model, tune_cfg, tuner, 1008, one_chip)
+    tuner._step.lower(*args).compile()
+    rung = _rung(tuner._step)
+    assert rung > 0 and sorted(tuner._step._rung_jits) == list(
+        range(rung + 1))
+    fresh = PromptTuner(model, tune_cfg)
+    fresh._step.lower(*args)
+    assert list(fresh._step._rung_jits) == [rung]
+
+
 def test_data_parallel_train_step_compiles_on_four_chips(topo):
     """chip_smoke.py --chips 4: global batch 64 over a 4-way data mesh;
     the prompt gradient must be all-reduced."""
@@ -179,7 +241,7 @@ def test_data_parallel_train_step_compiles_on_four_chips(topo):
             for k in ("params", "prompt_params", "opt_state", "batch")]
     assert args[3]["tokens"].sharding.spec[0] == "data"
     fn, _ = make_train_step(model, tune_cfg, batch_axes=data_axes(mesh))
-    compiled = jax.jit(fn).lower(*args).compile()
+    compiled = fn.lower(*args).compile()
     assert "all-reduce" in compiled.as_text()
     mem = compiled.memory_analysis()
     assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes

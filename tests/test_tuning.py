@@ -1,11 +1,16 @@
 """LPT algorithms: soft prompt + prefix (reparameterized) variants."""
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.config import TuneConfig
+from repro.configs import smoke_config
 from repro.data import LoaderConfig, TaskLoader
+from repro.models import build_model
+from repro.train.remat import SAVE_LADDER
 from repro.tuning import PromptTuner, activation_features
 
 
@@ -75,3 +80,56 @@ def test_init_prompt_from_tokens(pre_base):
     expected = np.asarray(pre.params["embedding"])[np.asarray(toks)]
     np.testing.assert_allclose(np.asarray(pp["soft_prompt"]), expected,
                                rtol=1e-6)
+
+
+# GQA + SwiGLU (gate and up tagged), MHA + GELU (up only)
+LAYER_TYPES = {"gqa_swiglu": ("qwen2-7b", {}),
+               "mha_gelu": ("gpt2-large", {"num_kv_heads": 4})}
+
+
+@functools.lru_cache(maxsize=None)
+def _rung_setup(layers, dtype):
+    arch, over = LAYER_TYPES[layers]
+    cfg = smoke_config(arch).with_overrides(remat=True, dtype=dtype,
+                                            param_dtype=dtype, **over)
+    model = build_model(cfg)
+    params = model.init(jax.random.key(0))
+    tuner = PromptTuner(model, TuneConfig(prompt_len=4, batch_size=2))
+    rng = np.random.default_rng(0)
+    pp = {"soft_prompt": jnp.asarray(
+        rng.normal(0, 0.1, (4, cfg.d_model)), jnp.float32)}
+    batch = {k: jnp.asarray(rng.integers(3, cfg.vocab_size, (2, 9)),
+                            jnp.int32) for k in ("tokens", "labels")}
+    batch["mask"] = jnp.ones((2, 9), jnp.float32)
+    return model, tuner, (pp, tuner.init_opt(pp), params, batch)
+
+
+def _run_rung(layers, dtype, rung):
+    """Loss, Adam's first moment (0.1 x the prompt gradient) and the
+    compiled text of the tuner's step on ``rung``."""
+    _, tuner, args = _rung_setup(layers, dtype)
+    jitted = tuner._step._jit(rung)
+    _, opt, loss = jitted(*args)
+    text = jitted.lower(*args).compile().as_text()
+    return float(loss), np.asarray(opt.mu["soft_prompt"]), text
+
+
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+@pytest.mark.parametrize("layers", sorted(LAYER_TYPES))
+@pytest.mark.parametrize("rung", range(len(SAVE_LADDER)))
+def test_saving_outputs_changes_no_number(rung, layers, dtype):
+    """Each rung of the save ladder gives the loss and prompt gradient of
+    full rematerialisation (the last rung) and recomputes fewer dots;
+    the forward-only score program lowers as without a save policy."""
+    model, tuner, (pp, _, params, batch) = _rung_setup(layers, dtype)
+    last = len(SAVE_LADDER) - 1
+    loss, mu, text = _run_rung(layers, dtype, rung)
+    full_loss, full_mu, full_text = _run_rung(layers, dtype, last)
+    np.testing.assert_allclose(loss, full_loss, rtol=1e-6, atol=0)
+    np.testing.assert_allclose(mu, full_mu, rtol=1e-6, atol=0)
+    assert np.abs(mu).max() > 0
+    if rung < last:
+        assert text.count(" dot(") < full_text.count(" dot(")
+    saving = PromptTuner(model.saving(SAVE_LADDER[rung]), tuner.tune_cfg)
+    assert (saving._score.lower(pp, params, batch).as_text()
+            == tuner._score.lower(pp, params, batch).as_text())
