@@ -109,17 +109,33 @@ def masked_ce(logits, labels, mask):
 
 
 class Reference:
-    """One configuration's reference, driven layer by layer."""
+    """One configuration's reference, driven layer by layer.
+
+    A family with one kind of layer defines ``layer_shapes(cfg)`` and
+    ``block(cfg, w, x, mode)``. A family with more than one defines
+    ``kinds(cfg)``, one kind per layer in order, and takes the kind as a
+    last argument of both. Layer ``i``'s tensors are drawn at global index
+    ``i`` (``weights.py``); a stack of routed experts from the file's
+    ``moe.expert_offset``."""
 
     def __init__(self, family, cfg, words, mode=None):
         self.fam, self.cfg, self.words, self.mode = family, cfg, words, mode
         dt = cfg["param_dtype"]
-        layer_shapes = family.layer_shapes(cfg)
         top_shapes = family.top_shapes(cfg)
+        experts = weights.expert_offset(cfg)
+        if hasattr(family, "kinds"):
+            self.kinds = list(family.kinds(cfg))
+            if len(self.kinds) != cfg["num_layers"]:
+                raise ValueError(f"{len(self.kinds)} layer kinds for "
+                                 f"{cfg['num_layers']} layers")
+        else:
+            self.kinds = [None] * cfg["num_layers"]
 
-        def layer(words, i, x):
-            w = weights.group(words, layer_shapes, dt, i)
-            return family.block(cfg, w, x, mode)
+        def layer(kind, words, i, x):
+            of = () if kind is None else (kind,)
+            w = weights.group(words, family.layer_shapes(cfg, *of), dt, i,
+                              experts)
+            return family.block(cfg, w, x, mode, *of)
 
         def head_loss(words, h, labels, mask):
             top = weights.group(words, top_shapes, dt)
@@ -131,13 +147,22 @@ class Reference:
                                 dt)
             return top["embedding"][tokens]
 
-        self._layer = jax.jit(layer)
-        self._layer_vjp = jax.jit(
-            lambda words, i, x, g: jax.vjp(
-                functools.partial(layer, words, i), x)[1](g)[0])
+        self._layers, self._layer_vjps = {}, {}
+        for kind in set(self.kinds):
+            fn = functools.partial(layer, kind)
+            self._layers[kind] = jax.jit(fn)
+            self._layer_vjps[kind] = jax.jit(
+                lambda words, i, x, g, fn=fn: jax.vjp(
+                    functools.partial(fn, words, i), x)[1](g)[0])
         self._head = jax.jit(head_loss)
         self._head_grad = jax.jit(jax.value_and_grad(head_loss, argnums=1))
         self._embed = jax.jit(embed)
+
+    def _layer(self, words, i, x):
+        return self._layers[self.kinds[i]](words, i, x)
+
+    def _layer_vjp(self, words, i, x, g):
+        return self._layer_vjps[self.kinds[i]](words, i, x, g)
 
     def inputs(self, prompts, tokens):
         """[prompt; token embeddings]; prompts (B, P, d), tokens (B, S)."""

@@ -35,6 +35,7 @@ import os  # noqa: E402
 import shutil  # noqa: E402
 import sys  # noqa: E402
 import tempfile  # noqa: E402
+import typing  # noqa: E402
 
 BENCH = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(BENCH)
@@ -69,6 +70,8 @@ from repro.tuning import PromptTuner  # noqa: E402
 CACHE_DIR = os.path.join(ROOT, ".jax_cache")
 # File keys that describe the configuration rather than set a model field.
 NOT_MODEL_FIELDS = ("name", "source")
+# Sections the benchmark's arithmetic reads from the file, not the registry.
+SECTIONS = ("moe", "mla", "ssm", "hybrid", "encdec")
 
 
 def log(msg: str) -> None:
@@ -398,12 +401,54 @@ DRIVERS = {"bank_lookup": BankLookup, "tune_jobs": TuneJobs}
 
 
 def program_config(cfg_file: dict):
-    """The program's ModelConfig as the configuration file states it."""
+    """The program's ModelConfig as the configuration file states it.
+
+    A top-level key that names a field sets it; others describe the
+    configuration and are ignored. A dict under a field whose value is a
+    dataclass (``moe``, ``mla``, ``ssm``, ``hybrid``, ...) replaces those
+    sub-fields of the registry's value (or of the dataclass's defaults,
+    where the registry has none); a sub-key that is not a field raises.
+    A section the registry's architecture has must be in the file, since
+    the benchmark's own arithmetic (``flops.py``) reads it from there."""
     base = get_config(cfg_file["program_arch"])
-    fields = {f.name for f in dataclasses.fields(base)}
-    over = {k: v for k, v in cfg_file.items()
-            if k in fields and k not in NOT_MODEL_FIELDS}
+    hints = typing.get_type_hints(type(base))
+    over = {}
+    for f in dataclasses.fields(base):
+        if f.name in NOT_MODEL_FIELDS:
+            continue
+        if f.name not in cfg_file:
+            if f.name in SECTIONS and getattr(base, f.name) is not None:
+                raise KeyError(f"configuration {cfg_file.get('name')!r} has "
+                               f"no {f.name!r} section, which "
+                               f"{cfg_file['program_arch']!r} has")
+            continue
+        value = cfg_file[f.name]
+        if isinstance(value, dict):
+            value = _section(f.name, getattr(base, f.name), hints[f.name],
+                             value)
+        over[f.name] = value
     return base.with_overrides(**over)
+
+
+def _section(key: str, current, hint, values: dict):
+    cls = type(current) if current is not None else next(
+        (a for a in typing.get_args(hint) if dataclasses.is_dataclass(a)),
+        None)
+    if not dataclasses.is_dataclass(cls):
+        raise TypeError(f"configuration key {key!r} is not a section")
+    known = {f.name for f in dataclasses.fields(cls)}
+    unknown = sorted(set(values) - known)
+    if unknown:
+        raise KeyError(f"configuration section {key!r} has no field "
+                       f"{unknown}; its fields are {sorted(known)}")
+    return dataclasses.replace(cls() if current is None else current,
+                               **values)
+
+
+def stacked_groups(model):
+    """Paths of the program's groups that stack layers, in layer order."""
+    groups = tuple(s.name for s in model.segments)
+    return groups + (("encoder/blocks",) if model.cfg.encdec else ())
 
 
 def reference_family(cfg_file: dict):
@@ -458,8 +503,9 @@ def run(bench: dict, workload: str, seed: int, seconds: float,
 
     model = build_model(program_config(cfg))
     words = generator.key_words(seed)
-    stacked = tuple(s.name for s in model.segments)
-    params = weights.program_params(words, model.abstract_params(), stacked)
+    params = weights.program_params(
+        words, model.abstract_params(), stacked_groups(model),
+        weights.expert_offset(cfg))
     jax.block_until_ready(params)
     driver = DRIVERS[mix["kind"]](Run(seed, cfg, mix, model, params))
     driver.setup()
@@ -494,7 +540,8 @@ def run(bench: dict, workload: str, seed: int, seconds: float,
         device.update(busy_s=red["busy_s"], window_s=red["window_s"])
         breakdown = {"device_ops": red["device_ops"],
                      "idle_gaps": red["idle_gaps"]}
-        ctx = {"counters": res["counters"], "window_s": window_s,
+        ctx = {"cfg": cfg, "mix": mix,
+               "counters": res["counters"], "window_s": window_s,
                "busy_s": red["busy_s"], "trace_window_s": red["window_s"],
                "required_flops": res["required_flops"],
                "peak_flops": peak["bf16_flops_per_s"] if peak else None}

@@ -17,11 +17,55 @@ SMOKE_SIZES = dict(num_layers=2, d_model=128, num_heads=4, d_ff=256,
                    vocab_size=512, dtype="float32", param_dtype="float32")
 
 
-def config(name):
-    cfg = load("configs", f"{name}.json")
-    cfg.update(SMOKE_SIZES, num_kv_heads=(4 if cfg["num_kv_heads"] ==
-                                          cfg["num_heads"] else 2))
+def shrink(cfg):
+    """A configuration file's smoke stand-in: small widths, and its
+    sections cut as ``repro.configs.smoke_config`` cuts the registry's (at
+    most 4 experts, small ranks and state, 2-5 layers)."""
+    mha = cfg["num_kv_heads"] == cfg["num_heads"]
+    cfg = dict(cfg, **SMOKE_SIZES, num_kv_heads=4 if mha else 2)
+    if "head_dim" in cfg:
+        cfg["head_dim"] = SMOKE_SIZES["d_model"] // SMOKE_SIZES["num_heads"]
+    if "moe" in cfg:
+        m = dict(cfg["moe"], num_experts=4, top_k=2, d_ff_expert=128)
+        m["num_shared_experts"] = min(cfg["moe"]["num_shared_experts"], 1)
+        m["first_dense_layers"] = min(cfg["moe"]["first_dense_layers"], 1)
+        if "experts_held" in m:
+            whole = cfg["moe"]["experts_held"] == cfg["moe"]["num_experts"]
+            m["experts_held"] = 4 if whole else 2
+            m["expert_offset"] = min(m.get("expert_offset", 0),
+                                     4 - m["experts_held"])
+        cfg["moe"] = m
+        # the leading dense layers as wide as top-k plus shared experts,
+        # as in the published models
+        cfg["d_ff"] = m["d_ff_expert"] * (m["top_k"]
+                                          + m["num_shared_experts"])
+        cfg["num_layers"] = m["first_dense_layers"] + 2
+    if "mla" in cfg:
+        # a q projected straight from d_model (rank 0) stays so
+        q_rank = 48 if cfg["mla"].get("q_lora_rank", 1) else 0
+        cfg["mla"] = dict(cfg["mla"], kv_lora_rank=64, q_lora_rank=q_rank,
+                          qk_nope_head_dim=32, qk_rope_head_dim=16,
+                          v_head_dim=32)
+    if "ssm" in cfg:
+        cfg["ssm"] = dict(cfg["ssm"], state_size=32, num_heads=0,
+                          chunk_size=16, expand=2)
+    if "hybrid" in cfg:
+        cfg["hybrid"] = dict(cfg["hybrid"], attn_every=2)
+        cfg["num_layers"] = 5
     return cfg
+
+
+def config(name):
+    return shrink(load("configs", f"{name}.json"))
+
+
+# A configuration with latent attention, routed experts and two kinds of
+# layer, kept as test data (no cell runs it yet)
+DEEPSEEK = "deepseek-v2-236b.stage"
+
+
+def data_config(name):
+    return shrink(load("tests", "data", f"{name}.json"))
 
 
 def traffic(name):

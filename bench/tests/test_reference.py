@@ -10,6 +10,7 @@ import pytest
 import generator
 import run
 import smoke
+import two_kinds
 import weights
 from reference.common import Reference, masked_ce
 
@@ -103,6 +104,47 @@ def test_layer_at_a_time_equals_whole_model(name):
     # layer moves it by O(1)
     np.testing.assert_allclose(g2, g, rtol=1e-3,
                                atol=1e-3 * float(jnp.abs(g).max()))
+
+
+def test_two_kinds_of_layer_equal_the_whole_model():
+    """A family with two kinds of layer: the layer-at-a-time loss, prompt
+    gradient and score equal one differentiated function of the model
+    written out whole, each layer drawn at its global index."""
+    cfg = dict(smoke.config("qwen2-7b.14of28"), num_layers=5,
+               pattern=["attn", "mlp", "mlp", "attn", "mlp"])
+    mix = smoke.traffic("bank_routed")
+    words = generator.key_words(SEED)
+    prompt = jnp.asarray(generator.prompt(SEED, "k", 0, mix["prompt_len"],
+                                          cfg["d_model"]))
+    batch = generator.eval_rows(SEED, 3, mix, cfg["vocab_size"])
+    dt, P = cfg["param_dtype"], prompt.shape[0]
+    layers = [(kind, weights.group(words, two_kinds.layer_shapes(cfg, kind),
+                                   dt, i))
+              for i, kind in enumerate(cfg["pattern"])]
+    top = weights.group(words, two_kinds.top_shapes(cfg), dt)
+
+    def whole(p):
+        B = batch["tokens"].shape[0]
+        x = jnp.concatenate([jnp.broadcast_to(p[None], (B, *p.shape)),
+                             top["embedding"][batch["tokens"]]], 1)
+        for kind, w in layers:
+            x = two_kinds.block(cfg, w, x, None, kind)
+        h = two_kinds.final(cfg, top, x[:, P:])
+        return masked_ce(two_kinds.logits(cfg, top, h, None),
+                         batch["labels"], batch["mask"])
+
+    with jax.default_matmul_precision("highest"):
+        loss, g = jax.value_and_grad(whole)(prompt)
+        ref = Reference(two_kinds, cfg, words)
+        l2, g2 = ref.loss_and_grad(prompt, batch)
+        s, = ref.scores([np.asarray(prompt)], [batch])
+    assert ref.kinds == cfg["pattern"]
+    assert l2 == pytest.approx(float(loss), rel=1e-6)
+    assert s == pytest.approx(float(loss), rel=1e-6)
+    np.testing.assert_allclose(g2, g, rtol=1e-3,
+                               atol=1e-3 * float(jnp.abs(g).max()))
+    with pytest.raises(ValueError, match="5 layer kinds for 4 layers"):
+        Reference(two_kinds, dict(cfg, num_layers=4), words)
 
 
 def test_weights_drawn_per_layer_match_the_program_tree():
